@@ -355,6 +355,10 @@ def main() -> None:
 if __name__ == "__main__":
     import argparse
 
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--segagg", action="store_true",
                     help="autotune segagg (block_n, block_g) + crossover "

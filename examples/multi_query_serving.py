@@ -12,6 +12,7 @@ import dataclasses
 import jax
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import Strategy, UniformWindowArrival
 from repro.models.base import get_config
 from repro.models.lm import build_specs
@@ -19,6 +20,8 @@ from repro.models.params import init_params, num_params
 from repro.serve.engine import PrefillExecutor, WindowJob, serve_multi_jobs
 
 SEQ = 64
+
+enable_compile_cache()
 
 cfg = get_config("yi_6b").reduced()
 cfg = dataclasses.replace(cfg, vocab_size=1024)

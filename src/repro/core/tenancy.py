@@ -122,10 +122,12 @@ def fair_shares(
     remaining = {t: demand[t] for t in active}
     cap = capacity
     while active and cap > EPS:
-        wsum = sum(w(t) for t in active)
-        if wsum <= 0:
-            break
-        alloc = {t: cap * w(t) / wsum for t in active}
+        # Weights relative to the largest active one: with subnormal
+        # weights, cap * w / wsum can round past cap.
+        wmax = max(w(t) for t in active)
+        rel = {t: w(t) / wmax for t in active}
+        wsum = sum(rel.values())
+        alloc = {t: cap * rel[t] / wsum for t in active}
         saturated = [t for t in active if alloc[t] >= remaining[t] - 1e-12]
         if not saturated:
             for t in active:

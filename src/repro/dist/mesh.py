@@ -39,12 +39,10 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple, Union
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..core.runtime import Dispatch, WorkerBackend
 from ..kernels.segagg.ops import merge_panes, pane_composite_groups, segagg
-from .context import constrain, mesh_context
 from .sharding import batch_shard_extents, batch_spec, on_fallback
 
 # Donation is a best-effort hint: platforms without buffer aliasing (CPU)
@@ -101,6 +99,8 @@ class DeviceMesh:
         self.events: List[Dict] = []
         self._on_event = on_event
         self._jit_cache: Dict[Tuple, Callable] = {}
+        #: rows sent to each device by ``segagg``, keyed by device id
+        self.rows_placed: Dict[int, int] = {}
 
     # -- introspection ----------------------------------------------------
     @property
@@ -141,7 +141,7 @@ class DeviceMesh:
         fn = self._jit_cache.get(key)
         if fn is not None:
             return fn
-        mesh, axis = self.mesh, self.axis
+        axis = self.axis
 
         def per_shard(k: jax.Array, v: jax.Array) -> jax.Array:
             # Each device runs the SAME compiled single-device kernel over
@@ -150,20 +150,14 @@ class DeviceMesh:
             # cross-device combine IS merge_panes.
             return segagg(k, v, num_groups, backend=backend)[None]
 
-        sharded = shard_map(
+        sharded = jax.shard_map(
             per_shard,
-            mesh=mesh,
+            mesh=self.mesh,
             in_specs=(P(axis), P(axis, None)),
             out_specs=P(axis, None, None),
         )
-
-        def run(k: jax.Array, v: jax.Array) -> jax.Array:
-            with mesh_context(mesh):
-                k = constrain(k, "batch")
-                v = constrain(v, "batch", None)
-                return merge_panes(sharded(k, v))
-
-        fn = jax.jit(run, donate_argnums=(1,))
+        fn = jax.jit(lambda k, v: merge_panes(sharded(k, v)),
+                     donate_argnums=(1,))
         self._jit_cache[key] = fn
         return fn
 
@@ -178,24 +172,31 @@ class DeviceMesh:
         """GROUP-BY partial aggregation sharded across the mesh: rows split
         over the data axis, one ``segagg`` per device, partials merged.
         Bit-compatible with the single-device op for integer-valued f32
-        inputs; ``values`` is donated (see the module docstring)."""
-        keys = jnp.asarray(keys).astype(jnp.int32)
-        values = jnp.asarray(values)
+        inputs; ``values`` is donated (see the module docstring).
+
+        Rows are padded and split on the host and each device is sent only
+        its own rows; ``rows_placed`` counts them per device."""
+        keys = np.asarray(keys).astype(np.int32)
+        values = np.asarray(values)
         if values.ndim == 1:
             values = values[:, None]
         D = self.num_devices
         if D == 1:
-            return segagg(keys, values, num_groups, backend=backend)
+            return segagg(jnp.asarray(keys), jnp.asarray(values), num_groups,
+                          backend=backend)
         N, V = keys.shape[0], values.shape[1]
         Np = -(-max(N, 1) // D) * D
         if Np != N:
-            keys = jnp.concatenate(
-                [keys, jnp.full((Np - N,), num_groups, jnp.int32)]
-            )
-            values = jnp.concatenate(
-                [values, jnp.zeros((Np - N, V), values.dtype)]
-            )
-        return self._sharded_segagg(num_groups, backend)(keys, values)
+            keys = np.concatenate(
+                [keys, np.full((Np - N,), num_groups, np.int32)])
+            values = np.concatenate(
+                [values, np.zeros((Np - N, V), values.dtype)])
+        k = jax.device_put(keys, self.batch_sharding(Np, 1))
+        v = jax.device_put(values, self.batch_sharding(Np, 2))
+        for shard in k.addressable_shards:
+            self.rows_placed[shard.device.id] = (
+                self.rows_placed.get(shard.device.id, 0) + shard.data.shape[0])
+        return self._sharded_segagg(num_groups, backend)(k, v)
 
     def pane_segagg(
         self,
@@ -210,13 +211,13 @@ class DeviceMesh:
         """Pane-partial aggregation sharded across the mesh, via the same
         composite-key reduction as the single-device op: (N,) keys +
         pane_ids -> (num_panes, num_groups, V) per-pane group sums."""
-        values = jnp.asarray(values)
+        values = np.asarray(values)
         if values.ndim == 1:
             values = values[:, None]
         total = pane_composite_groups(num_panes, num_groups)
         composite = (
-            jnp.asarray(pane_ids).astype(jnp.int32) * num_groups
-            + jnp.asarray(keys).astype(jnp.int32)
+            np.asarray(pane_ids).astype(np.int32) * num_groups
+            + np.asarray(keys).astype(np.int32)
         )
         flat = self.segagg(composite, values, total, backend=backend)
         return flat.reshape(num_panes, num_groups, values.shape[1])

@@ -103,15 +103,19 @@ def _segagg_xla_matmul(keys: jax.Array, values: jax.Array,
     vals_p = jnp.zeros((Np, V), jnp.float32).at[:N].set(
         values.astype(jnp.float32))
     gids = jnp.arange(num_groups, dtype=jnp.int32)
+    keys_b = keys_p.reshape(-1, block)
+    vals_b = vals_p.reshape(-1, block, V)
 
-    def body(acc, kv):
-        k, v = kv
+    def block_sum(k, v):
         onehot = (k[:, None] == gids[None, :]).astype(jnp.float32)
-        return acc + onehot.T @ v, None
+        return onehot.T @ v
 
+    # The first block's partial seeds the carry, so the carry has the type
+    # of the data (under shard_map: varying over the mesh axis), which a
+    # zeros seed would not.
     out, _ = jax.lax.scan(
-        body, jnp.zeros((num_groups, V), jnp.float32),
-        (keys_p.reshape(-1, block), vals_p.reshape(-1, block, V)))
+        lambda acc, kv: (acc + block_sum(*kv), None),
+        block_sum(keys_b[0], vals_b[0]), (keys_b[1:], vals_b[1:]))
     return out
 
 

@@ -1,8 +1,9 @@
-"""Pure-jnp oracle for the segagg kernel."""
+"""Oracles for the segagg kernel: pure jnp, and plain numpy."""
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 
 def segagg_ref(keys: jax.Array, values: jax.Array, num_groups: int) -> jax.Array:
@@ -27,3 +28,15 @@ def pane_segagg_ref(keys: jax.Array, values: jax.Array, pane_ids: jax.Array,
         values.astype(jnp.float32), composite,
         num_segments=num_panes * num_groups)
     return flat.reshape(num_panes, num_groups, values.shape[-1])
+
+
+def segagg_numpy(keys, values, num_groups: int) -> np.ndarray:
+    """Plain numpy oracle, independent of JAX: float64 ``np.bincount`` with
+    weights per value column -> (num_groups, V) float64 group sums."""
+    keys = np.asarray(keys)
+    values = np.asarray(values, np.float64)
+    if values.ndim == 1:
+        values = values[:, None]
+    return np.stack([np.bincount(keys, weights=values[:, j],
+                                 minlength=num_groups)
+                     for j in range(values.shape[1])], axis=1)

@@ -11,7 +11,7 @@ on the MXU:
     partial[g, v] = sum_i  [keys_i == g] * values[i, v]
 
 Grid: (num_group_blocks, num_row_blocks).  Each instance builds the
-(block_n x block_g) one-hot membership matrix in VMEM from an iota compare
+(block_g x block_n) one-hot membership matrix in VMEM from an iota compare
 (never in HBM) and contracts it with the (block_n x V) value block on the
 MXU, accumulating into the (block_g x V) output block across the row-block
 grid dimension (the sequential minor axis on TPU).  Work is O(N·G·V) MXU
@@ -35,9 +35,10 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-BLOCK_N = 512    # default rows per block
-BLOCK_G = 256    # default groups per block (lane-dim multiple of 128)
+BLOCK_N = 1024   # default rows per block (lane-dim multiple of 128)
+BLOCK_G = 256    # default groups per block (sublane-dim multiple of 8)
 # value width is padded to the 128-lane MXU boundary by ops.segagg
 
 # VMEM budget for the scatter variant's resident (G, V) accumulator
@@ -49,19 +50,21 @@ def _segagg_matmul_kernel(keys_ref, values_ref, out_ref, *, block_g: int):
     gi = pl.program_id(0)
     ni = pl.program_id(1)
 
-    keys = keys_ref[...]                     # (block_n,) int32
+    keys = keys_ref[...]                     # (1, block_n) int32, rows on lanes
     vals = values_ref[...]                   # (block_n, V)
 
     g0 = gi * block_g
-    # (block_n, block_g) one-hot membership, built in VMEM.
+    # (block_g, block_n) one-hot membership, built in VMEM.
     gids = g0 + jax.lax.broadcasted_iota(
-        jnp.int32, (keys.shape[0], block_g), 1)
-    onehot = (keys[:, None] == gids).astype(vals.dtype)
+        jnp.int32, (block_g, keys.shape[1]), 0)
+    onehot = (gids == keys).astype(vals.dtype)
 
-    # MXU contraction: (block_g, block_n) @ (block_n, V) -> (block_g, V)
-    partial = jax.lax.dot_general(
+    # MXU contraction: (block_g, block_n) @ (block_n, V) -> (block_g, V).
+    # HIGHEST keeps f32 values at f32 precision (the default may feed the
+    # MXU bf16, which would round float sums such as TPC-Q6's revenue).
+    partial = jax.lax.dot(
         onehot, vals,
-        dimension_numbers=(((0,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32,
     )
 
@@ -79,15 +82,14 @@ def _segagg_scatter_kernel(keys_ref, values_ref, out_ref):
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    keys = keys_ref[...]                     # (block_n,) int32
-    vals = values_ref[...].astype(jnp.float32)
-
     def body(i, _):
-        # out[key_i] += value_i — dynamic single-row accumulate.
-        out_ref[pl.ds(keys[i], 1), :] += vals[i][None, :]
+        # out[key_i] += value_i — the key is a scalar read from SMEM, the
+        # row a dynamic single-row accumulate into the VMEM accumulator.
+        out_ref[pl.ds(keys_ref[i], 1), :] += (
+            values_ref[pl.ds(i, 1), :].astype(jnp.float32))
         return 0
 
-    jax.lax.fori_loop(0, keys.shape[0], body, 0)
+    jax.lax.fori_loop(0, keys_ref.shape[0], body, 0)
 
 
 @functools.partial(jax.jit, static_argnums=(2, 3, 4, 5, 6))
@@ -102,16 +104,22 @@ def segagg_pallas(keys: jax.Array, values: jax.Array, num_groups: int,
     one-hot MXU matmul vs the sequential scatter-add variant."""
     N, V = values.shape
     assert N % block_n == 0, (N, block_n)
+    # Under shard_map the output varies over the same mesh axes as the rows.
+    out_shape = jax.ShapeDtypeStruct((num_groups, V), jnp.float32,
+                                     vma=jax.typeof(values).vma)
     if formulation == "scatter":
         return pl.pallas_call(
             _segagg_scatter_kernel,
             grid=(N // block_n,),
             in_specs=[
-                pl.BlockSpec((block_n,), lambda n: (n,)),
+                # Keys are read one scalar per row: SMEM, one block at a
+                # time (the whole key array would not fit SMEM).
+                pl.BlockSpec((block_n,), lambda n: (n,),
+                             memory_space=pltpu.SMEM),
                 pl.BlockSpec((block_n, V), lambda n: (n, 0)),
             ],
             out_specs=pl.BlockSpec((num_groups, V), lambda n: (0, 0)),
-            out_shape=jax.ShapeDtypeStruct((num_groups, V), jnp.float32),
+            out_shape=out_shape,
             interpret=interpret,
         )(keys, values)
     assert num_groups % block_g == 0, (num_groups, block_g)
@@ -120,10 +128,12 @@ def segagg_pallas(keys: jax.Array, values: jax.Array, num_groups: int,
         functools.partial(_segagg_matmul_kernel, block_g=block_g),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((block_n,), lambda g, n: (n,)),
+            # Keys as one (1, N) row: rows on the 128 lanes, which Mosaic
+            # tiles for any block_n that is a multiple of 128.
+            pl.BlockSpec((1, block_n), lambda g, n: (0, n)),
             pl.BlockSpec((block_n, V), lambda g, n: (n, 0)),
         ],
         out_specs=pl.BlockSpec((block_g, V), lambda g, n: (g, 0)),
-        out_shape=jax.ShapeDtypeStruct((num_groups, V), jnp.float32),
+        out_shape=out_shape,
         interpret=interpret,
-    )(keys, values)
+    )(keys.reshape(1, N), values)

@@ -31,7 +31,6 @@ unbiased scaled estimates whose error bound the scheduler reported in
 from __future__ import annotations
 
 import dataclasses
-import functools
 import time
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -41,6 +40,7 @@ import numpy as np
 
 from ..core import (
     CostModelBase,
+    ExecutorPool,
     LinearCostModel,
     Planner,
     Query,
@@ -56,6 +56,7 @@ from ..core import (
 from ..core.runtime import BaseExecutor, execute_plan
 from ..data.tpch import AnalyticsQuery, StreamScale
 from ..dist.mesh import MeshBackend
+from ..kernels.segagg.ops import pane_segagg, segagg
 
 
 @dataclasses.dataclass
@@ -64,26 +65,14 @@ class BatchResult:
     seconds: float
 
 
-@functools.partial(jax.jit, static_argnames="num_groups")
-def _segagg_ref_jit(keys, values, num_groups: int):
-    """Module-level jit so the compile cache is shared across ALL
-    ``AnalyticsExecutor`` instances: one compile per (num_groups, batch
-    shape), not one per executor.  (A per-instance ``jax.jit(lambda ...)``
-    defeats the cache — every fresh lambda is a new callable, and
-    ``measure_cost_model`` alone builds ~8 executors.)"""
-    from ..kernels.segagg.ref import segagg_ref
-
-    return segagg_ref(keys, values, num_groups)
-
-
 class AnalyticsExecutor:
     """Executes one AnalyticsQuery in intermittent batches.
 
-    ``backend=`` selects the segagg execution path (``"auto"`` → compiled
-    kernel for the platform; ``"interpret"`` → the Pallas interpreter, the
-    pre-dispatch behaviour) — see ``repro.kernels.segagg.ops``.  Only
-    consulted with ``use_kernel=True``; the default path is the jnp
-    reference.
+    Every batch runs the dispatched ``segagg`` kernel.  ``backend=``
+    selects its execution path (``"auto"`` → compiled kernel for the
+    platform; ``"pallas"`` → the compiled Pallas kernel, TPU/GPU only;
+    ``"interpret"`` → the Pallas interpreter) — see
+    ``repro.kernels.segagg.ops``.
 
     ``mesh=`` (a ``repro.dist.DeviceMesh``) routes every scan through the
     SHARDED kernel path: rows split over the mesh's data axis, one segagg
@@ -92,12 +81,10 @@ class AnalyticsExecutor:
     association); ``mesh=None`` is byte-for-byte the pre-mesh behaviour."""
 
     def __init__(self, query: AnalyticsQuery, scale: StreamScale,
-                 use_kernel: bool = False, backend: Optional[str] = None,
-                 mesh=None):
+                 backend: Optional[str] = None, mesh=None):
         self.query = query
         self.scale = scale
         self.num_groups = query.num_groups(scale)
-        self.use_kernel = use_kernel
         self.backend = backend
         self.mesh = mesh
         # Partials keyed by slot (tuple offset when driven by the runtime
@@ -107,13 +94,9 @@ class AnalyticsExecutor:
         if mesh is not None:
             self._agg = lambda k, v: mesh.segagg(k, v, self.num_groups,
                                                  backend=backend)
-        elif use_kernel:
-            from ..kernels.segagg.ops import segagg
-
-            self._agg = lambda k, v: segagg(k, v, self.num_groups,
-                                            backend=backend)
         else:
-            self._agg = lambda k, v: _segagg_ref_jit(k, v, self.num_groups)
+            self._agg = lambda k, v: segagg(jnp.asarray(k), jnp.asarray(v),
+                                            self.num_groups, backend=backend)
 
     def process_batch(self, records: Dict[str, np.ndarray],
                       slot: Optional[int] = None,
@@ -127,7 +110,7 @@ class AnalyticsExecutor:
         if weights is not None:
             vals = vals * np.asarray(weights, np.float32).reshape(-1, 1)
         t0 = time.perf_counter()
-        part = self._agg(jnp.asarray(keys), jnp.asarray(vals))
+        part = self._agg(keys, vals)
         part = np.asarray(part)  # spill to host; device buffers released
         dt = time.perf_counter() - t0
         if slot is None:  # sequential mode: next free key, never clobber
@@ -201,13 +184,12 @@ class AnalyticsRuntimeExecutor(BaseExecutor):
         self,
         jobs: Dict[str, Tuple[AnalyticsQuery, Sequence[Dict[str, np.ndarray]]]],
         scale: StreamScale,
-        use_kernel: bool = False,
         backend: Optional[str] = None,
         mesh=None,
     ):
         super().__init__()
         self._jobs = {
-            qid: (AnalyticsExecutor(aq, scale, use_kernel, backend, mesh),
+            qid: (AnalyticsExecutor(aq, scale, backend, mesh),
                   files)
             for qid, (aq, files) in jobs.items()
         }
@@ -281,7 +263,6 @@ class SharedAnalyticsExecutor(BaseExecutor):
         stream_files: Sequence[Dict[str, np.ndarray]],
         scale: StreamScale,
         book,  # repro.core.panes.SharedBook (shared with the runtime loop)
-        use_kernel: bool = False,
         backend: Optional[str] = None,
         mesh=None,
     ):
@@ -290,7 +271,6 @@ class SharedAnalyticsExecutor(BaseExecutor):
         self.files = list(stream_files)
         self.num_groups = query.num_groups(scale)
         self.book = book
-        self.use_kernel = use_kernel
         self.backend = backend
         self.mesh = mesh
         # query_id -> {local offset: partial}: straggler-idempotent, like
@@ -301,19 +281,14 @@ class SharedAnalyticsExecutor(BaseExecutor):
 
     # -- physical helpers ------------------------------------------------
     def _scan(self, records: Dict[str, np.ndarray]) -> np.ndarray:
-        from ..kernels.segagg.ops import segagg
-
         keys = np.asarray(self.aquery.key_fn(records), np.int32)
         vals = np.asarray(self.aquery.value_fn(records), np.float32)
         if self.mesh is not None:
             part = self.mesh.segagg(keys, vals, self.num_groups,
                                     backend=self.backend)
-        elif self.use_kernel:
+        else:
             part = segagg(jnp.asarray(keys), jnp.asarray(vals),
                           self.num_groups, backend=self.backend)
-        else:
-            part = _segagg_ref_jit(jnp.asarray(keys), jnp.asarray(vals),
-                                   self.num_groups)
         return np.asarray(part)
 
     def _scan_panes(self, stream: str, first_pane: int, count: int,
@@ -321,8 +296,6 @@ class SharedAnalyticsExecutor(BaseExecutor):
         """Scan ``count`` contiguous panes in one ``pane_segagg`` pass,
         deposit each pane's partial, and return their sum (this caller's
         share of the batch)."""
-        from ..kernels.segagg.ops import pane_segagg
-
         lo = first_pane * width
         chunk = self.files[lo: lo + count * width]
         records = concat_files(chunk)
@@ -335,10 +308,6 @@ class SharedAnalyticsExecutor(BaseExecutor):
         pane_of_file = np.repeat(
             np.arange(count, dtype=np.int32), width)[: len(chunk)]
         pane_ids = np.repeat(pane_of_file, sizes).astype(np.int32)
-        # The pane pass always runs through the dispatched kernel (there is
-        # no jnp ref fast path for pane partials): pre-PR-8 this hardcoded
-        # the interpreter, so every shared scan paid interpreter overhead —
-        # now the compiled backend does the physical work being measured.
         if self.mesh is not None:
             parts = np.asarray(self.mesh.pane_segagg(
                 keys, vals, pane_ids, count, self.num_groups,
@@ -525,13 +494,12 @@ def _plan_query(query_id: str, num_files: int) -> Query:
 
 def run_plan(query: AnalyticsQuery, files: Sequence[Dict[str, np.ndarray]],
              plan: Schedule, scale: StreamScale,
-             use_kernel: bool = False,
              backend: Optional[str] = None,
              mesh=None) -> Tuple[np.ndarray, List[BatchResult], float]:
     """Execute a scheduler plan (batch sizes in FILES) against real files
     through the shared runtime loop (strict mode: replay the plan verbatim)."""
     rex = AnalyticsRuntimeExecutor({query.query_id: (query, files)}, scale,
-                                   use_kernel, backend, mesh)
+                                   backend, mesh)
     q = _plan_query(query.query_id, len(files))
     execute_plan(q, plan, rex, strict=True)
     return (
@@ -543,12 +511,11 @@ def run_plan(query: AnalyticsQuery, files: Sequence[Dict[str, np.ndarray]],
 
 def run_batched(query: AnalyticsQuery, files: Sequence[Dict[str, np.ndarray]],
                 batch_files: int, scale: StreamScale,
-                use_kernel: bool = False,
                 backend: Optional[str] = None,
                 mesh=None) -> Tuple[np.ndarray, float, int]:
     """Process in fixed-size batches of ``batch_files``; returns
     (result, total_seconds incl. final agg, num_batches)."""
-    ex = AnalyticsExecutor(query, scale, use_kernel, backend, mesh)
+    ex = AnalyticsExecutor(query, scale, backend, mesh)
     for i in range(0, len(files), batch_files):
         ex.process_batch(concat_files(files[i:i + batch_files]))
     result, agg_s = ex.finalize()
@@ -567,7 +534,6 @@ def run_session(
     deadline_offset: Optional[float] = None,
     policy: str = "llf-dynamic",
     calibrate: bool = True,
-    use_kernel: bool = False,
     backend: Optional[str] = None,
     mesh=None,
     forecast=None,
@@ -585,6 +551,11 @@ def run_session(
     the scheduler's cost model refits online from measured wall seconds
     (cost units == seconds, §1/§6.2), so a mis-measured offline model heals
     while the session runs.
+
+    ``mesh=`` (a ``repro.dist.DeviceMesh``) runs the windows on a
+    ``MeshAnalyticsBackend`` pool, one worker per device, each shard group
+    one fused call across the mesh.  To plan for it, pass
+    ``shard_across=mesh.num_devices`` and a ``ShardedCostModel``.
 
     Predictive-scheduling knobs (docs/API.md "Predictive scheduling"):
     ``forecast=`` (bool or ``repro.core.ForecastConfig``) turns on arrival
@@ -638,16 +609,19 @@ def run_session(
         rspec.window_query(w).query_id: (query, list(files))
         for w, files in enumerate(windows)
     }
-    executor = AnalyticsRuntimeExecutor(jobs, scale, use_kernel, backend,
-                                        mesh)
+    if mesh is None:
+        executor = physical = AnalyticsRuntimeExecutor(jobs, scale, backend)
+    else:
+        physical = MeshAnalyticsBackend(jobs, scale, mesh, backend)
+        executor = ExecutorPool(worker_backend=physical)
     session = Session(policy=policy, executor=executor, calibrate=calibrate,
                       forecast=forecast, **session_kw)
     session.submit(rspec)
     trace = session.run()
     results = {
-        w: executor.results[rspec.window_query(w).query_id]
+        w: physical.results[rspec.window_query(w).query_id]
         for w in range(len(windows))
-        if rspec.window_query(w).query_id in executor.results
+        if rspec.window_query(w).query_id in physical.results
     }
     return results, trace
 
@@ -663,7 +637,6 @@ def run_shared_jobs(
     share: bool = True,
     pane_tuples: Optional[int] = None,
     deadline_frac: float = 3.0,
-    use_kernel: bool = False,
     backend: Optional[str] = None,
     mesh=None,
     **policy_params,
@@ -710,8 +683,7 @@ def run_shared_jobs(
     else:
         specs, book = qs, SharedBook(pane_tuples=pane_tuples)
     executor = SharedAnalyticsExecutor(query, files, scale, book,
-                                       use_kernel=use_kernel, backend=backend,
-                                       mesh=mesh)
+                                       backend=backend, mesh=mesh)
     trace = run_loop(pol, specs, executor,
                      sharing=book if share else None)
     if share:
@@ -723,21 +695,20 @@ def measure_cost_model(query: AnalyticsQuery,
                        files: Sequence[Dict[str, np.ndarray]],
                        scale: StreamScale,
                        batch_sizes: Sequence[int] = (1, 4, 16, 64),
-                       use_kernel: bool = False,
                        backend: Optional[str] = None,
                        mesh=None) -> CostModelBase:
     """§6.2 calibration: measure execution time vs batch size, fit the
     piecewise-linear model (file units).  ``backend=`` picks the segagg
-    path being calibrated (with ``use_kernel=True``) — cost models fitted
-    here describe THAT backend's wall clock, so calibrate against the same
+    path being calibrated — cost models fitted here describe THAT
+    backend's wall clock, so calibrate against the same
     backend the session will execute on."""
     samples = []
     agg_samples = [(1, 0.0)]
     for bs in batch_sizes:
         bs = min(bs, len(files))
         # warmup: first call at each padded shape compiles
-        run_batched(query, files[:bs], bs, scale, use_kernel, backend, mesh)
-        ex = AnalyticsExecutor(query, scale, use_kernel, backend, mesh)
+        run_batched(query, files[:bs], bs, scale, backend, mesh)
+        ex = AnalyticsExecutor(query, scale, backend, mesh)
         reps = max(3, min(8, len(files) // bs))
         for i in range(reps):
             lo = (i * bs) % max(len(files) - bs, 1)
@@ -747,7 +718,7 @@ def measure_cost_model(query: AnalyticsQuery,
     # final-agg cost vs #batches
     for nb in (2, 8, 32):
         per = max(len(files) // nb, 1)
-        ex = AnalyticsExecutor(query, scale, use_kernel, backend, mesh)
+        ex = AnalyticsExecutor(query, scale, backend, mesh)
         for i in range(nb):
             ex.process_batch(concat_files(files[i * per: (i + 1) * per] or
                                           files[:1]))

@@ -10,6 +10,7 @@ import pytest
 
 from repro.core import Planner, Query, Strategy, TraceArrival, UniformWindowArrival
 from repro.data.tpch import PAPER_QUERIES, StreamScale, stream_files
+from repro.kernels.segagg.ref import segagg_numpy
 from repro.serve.analytics import (
     AnalyticsExecutor,
     concat_files,
@@ -29,6 +30,14 @@ def _files(stream: str, n: int = 48, seed: int = 3):
     return files, times
 
 
+def _numpy_groupby(query, files):
+    """Independent reference: the query's keys and values over all files,
+    aggregated by float64 ``np.bincount``."""
+    records = concat_files(files)
+    return segagg_numpy(query.key_fn(records), query.value_fn(records),
+                        query.num_groups(SCALE))
+
+
 class TestAnalyticsExecutor:
     @pytest.mark.parametrize("query", PAPER_QUERIES, ids=lambda q: q.query_id)
     def test_partials_equal_oneshot(self, query):
@@ -37,13 +46,14 @@ class TestAnalyticsExecutor:
         many, _, nb = run_batched(query, files, 5, SCALE)
         assert nb == 5
         np.testing.assert_allclose(one, many, rtol=1e-5, atol=1e-5)
+        np.testing.assert_allclose(many, _numpy_groupby(query, files),
+                                   rtol=1e-5, atol=1e-5)
 
     def test_kernel_path_matches_ref_path(self):
         query = PAPER_QUERIES[1]  # CQ2, 5 groups
         files, _ = _files(query.stream, 8)
-        ref, _, _ = run_batched(query, files, 4, SCALE, use_kernel=False)
-        ker, _, _ = run_batched(query, files, 4, SCALE, use_kernel=True)
-        np.testing.assert_allclose(ref, ker, rtol=1e-4, atol=1e-4)
+        ker, _, _ = run_batched(query, files, 4, SCALE)
+        assert np.array_equal(ker, _numpy_groupby(query, files))
 
     def test_scheduled_plan_executes_and_meets_deadline(self):
         query = PAPER_QUERIES[2]
@@ -54,26 +64,25 @@ class TestAnalyticsExecutor:
                   arr.wind_end + 1.5 * cm.cost(48), 48, cm, arr)
         plan = Planner(policy="single").schedule(q)
         result, log, agg_s = run_plan(query, files, plan, SCALE)
-        oneshot, _, _ = run_batched(query, files, 48, SCALE)
-        np.testing.assert_allclose(result, oneshot, rtol=1e-5)
+        assert np.array_equal(result, _numpy_groupby(query, files))
         assert sum(b.num_records for b in log) == sum(
             len(f["ts"]) for f in files)
 
     def test_jit_cache_shared_across_executors(self):
         """Regression: a per-instance ``jax.jit(lambda ...)`` recompiled the
         segagg kernel for EVERY AnalyticsExecutor; the module-level jitted
-        function must compile once per (num_groups, shape)."""
-        from repro.serve.analytics import _segagg_ref_jit
+        kernel must compile once per (num_groups, shape)."""
+        from repro.kernels.segagg.ops import _segagg_xla_matmul
 
-        query = PAPER_QUERIES[1]  # CQ2: 5 groups
+        query = PAPER_QUERIES[1]  # CQ2: 5 groups -> the XLA matmul on CPU
         files, _ = _files(query.stream, 6)
         batch = concat_files(files[:2])
-        before = _segagg_ref_jit._cache_size()
+        before = _segagg_xla_matmul._cache_size()
         for _ in range(3):
-            ex = AnalyticsExecutor(query, SCALE)
+            ex = AnalyticsExecutor(query, SCALE, backend="xla")
             ex.process_batch(batch)
             ex.process_batch(batch)
-        after = _segagg_ref_jit._cache_size()
+        after = _segagg_xla_matmul._cache_size()
         assert after - before <= 1  # ONE new entry at most, not one per executor
 
     def test_recurring_session_real_backend(self):
@@ -94,8 +103,7 @@ class TestAnalyticsExecutor:
                                      period=10.0, calibrate=True)
         assert sorted(results) == [0, 1]
         for w in range(nw):
-            ref, _, _ = run_batched(aq, windows[w], nf, SCALE)
-            np.testing.assert_allclose(results[w], ref, rtol=1e-5)
+            assert np.array_equal(results[w], _numpy_groupby(aq, windows[w]))
         series = trace.outcome_series(aq.query_id)
         assert [o.complete for o in series] == [True, True]
         kinds = [e.kind for e in trace.events]
@@ -131,10 +139,9 @@ class TestAnalyticsExecutor:
         assert len(phys.batch_log) == 2 * n_batches
         # ...but the offset-keyed partials were overwritten, not appended
         assert phys.num_batches == n_batches
-        # and the combined result is exactly the clean one-shot answer
-        oneshot, _, _ = run_batched(query, files, n, SCALE)
-        np.testing.assert_allclose(slow.results[q.query_id], oneshot,
-                                   rtol=1e-5)
+        # and the combined result is exactly the clean answer
+        assert np.array_equal(slow.results[q.query_id],
+                              _numpy_groupby(query, files))
 
 
 class TestCheckpoint:

@@ -42,8 +42,8 @@ from repro.dist import (
     weighted_shard_extents,
 )
 from repro.dist.sharding import batch_shard_extents, batch_spec
-from repro.kernels.segagg.ref import pane_segagg_ref, segagg_ref
-from repro.serve.analytics import MeshAnalyticsBackend, run_batched
+from repro.kernels.segagg.ref import pane_segagg_ref, segagg_numpy, segagg_ref
+from repro.serve.analytics import MeshAnalyticsBackend, concat_files
 
 NDEV = jax.device_count()
 
@@ -396,9 +396,10 @@ class TestMeshBackendEndToEnd:
         files = [(line if aq.stream == "lineitem" else o)
                  for _, o, line in
                  stream_files(seed=5, num_files=16, sc=self.SCALE)]
-        oneshot, _, _ = run_batched(aq, files, 16, self.SCALE)
-        assert np.array_equal(wb.results["q0"].ravel(),
-                              np.asarray(oneshot).ravel())
+        records = concat_files(files)
+        ref = segagg_numpy(aq.key_fn(records), aq.value_fn(records),
+                           aq.num_groups(self.SCALE))
+        assert np.array_equal(wb.results["q0"], ref)
 
     @needs_devices(2)
     def test_sharded_run_is_exact_and_fused(self):

@@ -116,7 +116,11 @@ def check_fair_shares(demand, weights, capacity):
     assert total_alloc <= capacity + 1e-6
     active = {t for t, d in demand.items()
               if d > 1e-9 and weights.get(t, 0.0) > 0}
-    wsum = sum(weights[t] for t in active)
+    # Weights relative to the largest, so that subnormal weights do not
+    # round the first-round slice past the capacity.
+    wmax = max((weights[t] for t in active), default=0.0)
+    rel = {t: weights[t] / wmax for t in active}
+    wsum = sum(rel.values())
     for t, d in demand.items():
         assert -1e-9 <= share[t] <= d + 1e-6
         if t not in active:
@@ -124,7 +128,7 @@ def check_fair_shares(demand, weights, capacity):
         elif wsum > 0:
             # Progressive filling only ever ADDS capacity to an unsatisfied
             # tenant, so everyone keeps at least the first-round slice.
-            floor = min(d, capacity * weights[t] / wsum)
+            floor = min(d, capacity * rel[t] / wsum)
             assert share[t] >= floor - 1e-6
     if sum(demand[t] for t in active) <= capacity + 1e-9:
         for t in active:
