@@ -44,6 +44,7 @@ import heapq
 import math
 from typing import Callable, Dict, List, Optional, Sequence, Set, Tuple, Union
 
+from .. import tracing
 from .api import Executor, SchedulingEvent, SchedulingPolicy
 from .arrivals import ArrivalModel
 from .cost_model import CostModelBase
@@ -310,7 +311,8 @@ class BaseExecutor:
 
     def submit_batch(self, query: Query, num_tuples: int, offset: int) -> float:
         dur = self._modelled_batch_cost(query, num_tuples)
-        self.last_batch_wall = self._execute(query, num_tuples, offset)
+        with tracing.span("executor.batch", query.query_id):
+            self.last_batch_wall = self._execute(query, num_tuples, offset)
         if self.last_batch_wall is not None:
             self.wall_seconds[query.query_id] = (
                 self.wall_seconds.get(query.query_id, 0.0) + self.last_batch_wall
@@ -320,7 +322,8 @@ class BaseExecutor:
 
     def finalize(self, query: Query, num_batches: int) -> float:
         agg = self._modelled_agg_cost(query, num_batches)
-        wall = self._finalize(query, num_batches)
+        with tracing.span("executor.finalize", query.query_id):
+            wall = self._finalize(query, num_batches)
         self.last_agg_wall = wall
         if wall is not None:
             self.wall_seconds[query.query_id] = (
@@ -336,7 +339,8 @@ class BaseExecutor:
         ``on_batch`` observers, so downstream consumers (calibration
         feedback) see exactly one settled measurement per batch, not the
         straggling outlier."""
-        wall = self._execute(query, num_tuples, offset)
+        with tracing.span("executor.batch", query.query_id):
+            wall = self._execute(query, num_tuples, offset)
         if wall is not None:
             self.wall_seconds[query.query_id] = (
                 self.wall_seconds.get(query.query_id, 0.0) + wall
@@ -1276,7 +1280,8 @@ class DynamicLoopCore:
             state.worker_weights = tuple(
                 getattr(executor, "worker_weights", None) or ()
             )
-        decision = self._decide(now)
+        with tracing.span("policy.decide"):
+            decision = self._decide(now)
         if decision.is_stop:
             return "stop"
         if decision.is_wait:

@@ -58,6 +58,7 @@ import dataclasses
 import math
 from typing import Callable, Dict, List, Optional, Tuple, Union
 
+from .. import tracing
 from .api import Executor, SchedulingPolicy, get_policy
 from .arrivals import ArrivalModel, ThinnedArrival, TraceArrival
 from .cost_model import CalibratingCostModel, SharedCostModel
@@ -1197,14 +1198,16 @@ class SessionRuntime:
     # -- dynamic path ---------------------------------------------------
     def _run_dynamic_until(self, horizon: float, max_steps: int) -> None:
         for _ in range(max_steps):
-            self._replenish()
-            status = self._core.tick(horizon)
-            self._drain_outcome_events()
-            if status == "wait":
-                # The loop just idled forward to the next readiness
-                # instant: free capacity forecast-driven pane pre-warming
-                # may spend (no-op unless forecast= AND sharing=).
-                self._prewarm()
+            with tracing.span("session.step"):
+                self._replenish()
+                status = self._core.tick(horizon)
+                self._drain_outcome_events()
+                if status == "wait":
+                    # The loop just idled forward to the next readiness
+                    # instant: free capacity forecast-driven pane
+                    # pre-warming may spend (no-op unless forecast= AND
+                    # sharing=).
+                    self._prewarm()
             if status == "horizon":
                 return
             if status == "stop" or (
@@ -1450,6 +1453,10 @@ class SessionRuntime:
     # Calibration feedback
     # ------------------------------------------------------------------
     def _observe(self, ex: BatchExecution) -> None:
+        with tracing.span("session.observe", ex.query_id):
+            self._observe_batch(ex)
+
+    def _observe_batch(self, ex: BatchExecution) -> None:
         shared = False
         if self.book is not None:
             shared = self.book.knows(ex.query_id)

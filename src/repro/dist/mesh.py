@@ -41,6 +41,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from .. import tracing
 from ..core.runtime import Dispatch, WorkerBackend
 from ..kernels.segagg.ops import merge_panes, pane_composite_groups, segagg
 from .sharding import batch_shard_extents, batch_spec, on_fallback
@@ -156,8 +157,12 @@ class DeviceMesh:
             in_specs=(P(axis), P(axis, None)),
             out_specs=P(axis, None, None),
         )
-        fn = jax.jit(lambda k, v: merge_panes(sharded(k, v)),
-                     donate_argnums=(1,))
+        def merged(k: jax.Array, v: jax.Array) -> jax.Array:
+            parts = sharded(k, v)
+            with jax.named_scope("mesh.merge"):
+                return merge_panes(parts)
+
+        fn = jax.jit(merged, donate_argnums=(1,))
         self._jit_cache[key] = fn
         return fn
 
@@ -182,21 +187,25 @@ class DeviceMesh:
             values = values[:, None]
         D = self.num_devices
         if D == 1:
-            return segagg(jnp.asarray(keys), jnp.asarray(values), num_groups,
-                          backend=backend)
+            with tracing.span("transfer"):
+                k, v = jnp.asarray(keys), jnp.asarray(values)
+            with tracing.span("kernel.dispatch"):
+                return segagg(k, v, num_groups, backend=backend)
         N, V = keys.shape[0], values.shape[1]
         Np = -(-max(N, 1) // D) * D
-        if Np != N:
-            keys = np.concatenate(
-                [keys, np.full((Np - N,), num_groups, np.int32)])
-            values = np.concatenate(
-                [values, np.zeros((Np - N, V), values.dtype)])
-        k = jax.device_put(keys, self.batch_sharding(Np, 1))
-        v = jax.device_put(values, self.batch_sharding(Np, 2))
+        with tracing.span("transfer"):
+            if Np != N:
+                keys = np.concatenate(
+                    [keys, np.full((Np - N,), num_groups, np.int32)])
+                values = np.concatenate(
+                    [values, np.zeros((Np - N, V), values.dtype)])
+            k = jax.device_put(keys, self.batch_sharding(Np, 1))
+            v = jax.device_put(values, self.batch_sharding(Np, 2))
         for shard in k.addressable_shards:
             self.rows_placed[shard.device.id] = (
                 self.rows_placed.get(shard.device.id, 0) + shard.data.shape[0])
-        return self._sharded_segagg(num_groups, backend)(k, v)
+        with tracing.span("kernel.dispatch"):
+            return self._sharded_segagg(num_groups, backend)(k, v)
 
     def pane_segagg(
         self,
@@ -286,9 +295,10 @@ class MeshBackend(WorkerBackend):
 
     def run_batch(self, query, num_tuples, offset, worker):
         start = self._clocks[worker]
-        t0 = time.perf_counter()
-        self._batch_execute(query, num_tuples, offset)
-        dt = time.perf_counter() - t0
+        with tracing.span("executor.batch", query.query_id):
+            t0 = time.perf_counter()
+            self._batch_execute(query, num_tuples, offset)
+            dt = time.perf_counter() - t0
         self.last_batch_wall = dt
         self._charge(query, dt)
         self._solo_tuples[worker] += num_tuples
@@ -301,9 +311,10 @@ class MeshBackend(WorkerBackend):
         # The fused call cannot start before the LAST claimed worker frees
         # (all devices participate in the shard_map).
         start = max(self._clocks[w] for w in workers)
-        t0 = time.perf_counter()
-        self._group_execute(query, sizes, base_offset, workers)
-        dt = time.perf_counter() - t0
+        with tracing.span("executor.batch", query.query_id):
+            t0 = time.perf_counter()
+            self._group_execute(query, sizes, base_offset, workers)
+            dt = time.perf_counter() - t0
         self.last_batch_wall = dt
         self._charge(query, dt)
         end = start + dt
@@ -314,9 +325,10 @@ class MeshBackend(WorkerBackend):
         )
 
     def run_agg(self, query, num_batches, worker, start, barrier):
-        t0 = time.perf_counter()
-        self._agg_execute(query, num_batches)
-        dt = time.perf_counter() - t0
+        with tracing.span("executor.finalize", query.query_id):
+            t0 = time.perf_counter()
+            self._agg_execute(query, num_batches)
+            dt = time.perf_counter() - t0
         self.last_agg_wall = dt
         self._charge(query, dt)
         if dt > 0:

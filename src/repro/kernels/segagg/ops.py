@@ -158,12 +158,15 @@ def segagg(keys: jax.Array, values: jax.Array, num_groups: int,
     Gp = _pad_to(num_groups + 1, block_g)   # +1 sacrificial group for padding
     Vp = _pad_to(V, 128)
     form = tuning.pick_formulation(be, N, num_groups, Vp, formulation)
-    keys_p = jnp.full((Np,), num_groups, jnp.int32).at[:N].set(
-        keys.astype(jnp.int32))
-    vals_p = jnp.zeros((Np, Vp), values.dtype).at[:N, :V].set(values)
-    out = segagg_pallas(keys_p, vals_p, Gp, be == "interpret",
-                        block_n, block_g, form)
-    return out[:num_groups, :V]
+    with jax.named_scope("segagg.pad"):
+        keys_p = jnp.full((Np,), num_groups, jnp.int32).at[:N].set(
+            keys.astype(jnp.int32))
+        vals_p = jnp.zeros((Np, Vp), values.dtype).at[:N, :V].set(values)
+    with jax.named_scope("segagg.kernel"):
+        out = segagg_pallas(keys_p, vals_p, Gp, be == "interpret",
+                            block_n, block_g, form)
+    with jax.named_scope("segagg.pad"):
+        return out[:num_groups, :V]
 
 
 def group_count(keys: jax.Array, num_groups: int,

@@ -31,6 +31,7 @@ unbiased scaled estimates whose error bound the scheduler reported in
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -38,6 +39,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import tracing
 from ..core import (
     CostModelBase,
     ExecutorPool,
@@ -91,12 +93,6 @@ class AnalyticsExecutor:
         # loop): re-queued stragglers overwrite instead of double-counting.
         self.partials: Dict[int, np.ndarray] = {}
         self.batch_log: List[BatchResult] = []
-        if mesh is not None:
-            self._agg = lambda k, v: mesh.segagg(k, v, self.num_groups,
-                                                 backend=backend)
-        else:
-            self._agg = lambda k, v: segagg(jnp.asarray(k), jnp.asarray(v),
-                                            self.num_groups, backend=backend)
 
     def process_batch(self, records: Dict[str, np.ndarray],
                       slot: Optional[int] = None,
@@ -104,14 +100,14 @@ class AnalyticsExecutor:
         """Compute one partial aggregate.  ``weights`` (per-record value
         multipliers) realize sampled scans under load shedding: each kept
         record is weighted by the inverse keep rate, making the partial a
-        Horvitz-Thompson estimate of the unsampled aggregate."""
-        keys = np.asarray(self.query.key_fn(records), np.int32)
-        vals = np.asarray(self.query.value_fn(records), np.float32)
-        if weights is not None:
-            vals = vals * np.asarray(weights, np.float32).reshape(-1, 1)
+        Horvitz-Thompson estimate of the unsampled aggregate.
+
+        The measured ``seconds`` (what calibration reads) start after the
+        key/value extraction: transfer, kernel and spill only."""
+        keys, vals = _extract(self.query, records, weights)
         t0 = time.perf_counter()
-        part = self._agg(keys, vals)
-        part = np.asarray(part)  # spill to host; device buffers released
+        part = _group_by(keys, vals, self.num_groups, self.backend, self.mesh)
+        part = _spill(part)  # device buffers released
         dt = time.perf_counter() - t0
         if slot is None:  # sequential mode: next free key, never clobber
             slot = len(self.partials)
@@ -125,11 +121,7 @@ class AnalyticsExecutor:
     def finalize(self) -> Tuple[np.ndarray, float]:
         """Final aggregation step (paper §2.1): combine the partials."""
         t0 = time.perf_counter()
-        total = (
-            np.sum(np.stack(list(self.partials.values())), axis=0)
-            if self.partials
-            else np.zeros((self.num_groups, 1), np.float32)
-        )
+        total = _merge(self.partials.values(), self.num_groups)
         return total, time.perf_counter() - t0
 
     @property
@@ -138,8 +130,57 @@ class AnalyticsExecutor:
 
 
 def concat_files(files: Sequence[Dict[str, np.ndarray]]) -> Dict[str, np.ndarray]:
-    keys = files[0].keys()
-    return {k: np.concatenate([f[k] for f in files]) for k in keys}
+    with tracing.span("prep.concat"):
+        keys = files[0].keys()
+        return {k: np.concatenate([f[k] for f in files]) for k in keys}
+
+
+# -- one scan's steps, shared by every scan path below ----------------------
+
+def _extract(query: AnalyticsQuery, records: Dict[str, np.ndarray],
+             weights: Optional[np.ndarray] = None
+             ) -> Tuple[np.ndarray, np.ndarray]:
+    """The scan's key and value columns on the host; ``weights`` scale
+    the values of a sampled scan (load shedding)."""
+    with tracing.span("prep.extract"):
+        keys = np.asarray(query.key_fn(records), np.int32)
+        vals = np.asarray(query.value_fn(records), np.float32)
+        if weights is not None:
+            vals = vals * np.asarray(weights, np.float32).reshape(-1, 1)
+    return keys, vals
+
+
+def _launch(fn, *host_arrays: np.ndarray) -> jax.Array:
+    """Hand host arrays to the default device and launch ``fn`` on them
+    (the mesh path spans its own sharded put and launch)."""
+    with tracing.span("transfer"):
+        arrays = [jnp.asarray(a) for a in host_arrays]
+    with tracing.span("kernel.dispatch"):
+        return fn(*arrays)
+
+
+def _group_by(keys: np.ndarray, vals: np.ndarray, num_groups: int,
+              backend: Optional[str], mesh) -> jax.Array:
+    """One GROUP-BY partial of host columns, left on the device."""
+    if mesh is not None:
+        return mesh.segagg(keys, vals, num_groups, backend=backend)
+    return _launch(functools.partial(segagg, num_groups=num_groups,
+                                     backend=backend), keys, vals)
+
+
+def _spill(part: jax.Array) -> np.ndarray:
+    """Wait for a partial and copy it to the host."""
+    with tracing.span("spill"):
+        return np.asarray(part)
+
+
+def _merge(parts, num_groups: int) -> np.ndarray:
+    """The final aggregation: the sum of a window's partials."""
+    with tracing.span("finalize.merge"):
+        parts = [np.asarray(p) for p in parts]
+        if not parts:
+            return np.zeros((num_groups, 1), np.float32)
+        return np.sum(np.stack(parts), axis=0)
 
 
 def _is_thinned(arrival) -> bool:
@@ -177,7 +218,8 @@ class AnalyticsRuntimeExecutor(BaseExecutor):
     ``jobs`` maps a scheduler query_id to its (AnalyticsQuery, files); batch
     tuple units are FILES (exactly the paper's setup).  The modelled clock
     advances by cost-model time; measured wall seconds are recorded per
-    query (``wall_seconds``) and final results land in ``results``.
+    query (``wall_seconds``; the last final aggregation's in
+    ``last_agg_wall``) and final results land in ``results``.
     """
 
     def __init__(
@@ -194,7 +236,6 @@ class AnalyticsRuntimeExecutor(BaseExecutor):
             for qid, (aq, files) in jobs.items()
         }
         self.results: Dict[str, np.ndarray] = {}
-        self.agg_seconds: Dict[str, float] = {}
 
     def physical(self, query_id: str) -> AnalyticsExecutor:
         return self._jobs[query_id][0]
@@ -229,7 +270,6 @@ class AnalyticsRuntimeExecutor(BaseExecutor):
         ex, _ = self._jobs[query.query_id]
         total, agg_s = ex.finalize()
         self.results[query.query_id] = total
-        self.agg_seconds[query.query_id] = agg_s
         return agg_s
 
 
@@ -277,19 +317,12 @@ class SharedAnalyticsExecutor(BaseExecutor):
         # AnalyticsExecutor.partials.
         self._acc: Dict[str, Dict[int, np.ndarray]] = {}
         self.results: Dict[str, np.ndarray] = {}
-        self.agg_seconds: Dict[str, float] = {}
 
     # -- physical helpers ------------------------------------------------
     def _scan(self, records: Dict[str, np.ndarray]) -> np.ndarray:
-        keys = np.asarray(self.aquery.key_fn(records), np.int32)
-        vals = np.asarray(self.aquery.value_fn(records), np.float32)
-        if self.mesh is not None:
-            part = self.mesh.segagg(keys, vals, self.num_groups,
-                                    backend=self.backend)
-        else:
-            part = segagg(jnp.asarray(keys), jnp.asarray(vals),
-                          self.num_groups, backend=self.backend)
-        return np.asarray(part)
+        keys, vals = _extract(self.aquery, records)
+        return _spill(_group_by(keys, vals, self.num_groups, self.backend,
+                                self.mesh))
 
     def _scan_panes(self, stream: str, first_pane: int, count: int,
                     width: int, by: str) -> np.ndarray:
@@ -299,8 +332,7 @@ class SharedAnalyticsExecutor(BaseExecutor):
         lo = first_pane * width
         chunk = self.files[lo: lo + count * width]
         records = concat_files(chunk)
-        keys = np.asarray(self.aquery.key_fn(records), np.int32)
-        vals = np.asarray(self.aquery.value_fn(records), np.float32)
+        keys, vals = _extract(self.aquery, records)
         # Row counts straight from the record arrays (every field of a file
         # has one row per record) — running key_fn per file would pay a
         # second full key pass inside the timed region.
@@ -309,15 +341,13 @@ class SharedAnalyticsExecutor(BaseExecutor):
             np.arange(count, dtype=np.int32), width)[: len(chunk)]
         pane_ids = np.repeat(pane_of_file, sizes).astype(np.int32)
         if self.mesh is not None:
-            parts = np.asarray(self.mesh.pane_segagg(
-                keys, vals, pane_ids, count, self.num_groups,
-                backend=self.backend,
-            ))
+            parts = self.mesh.pane_segagg(keys, vals, pane_ids, count,
+                                          self.num_groups, backend=self.backend)
         else:
-            parts = np.asarray(pane_segagg(
-                jnp.asarray(keys), jnp.asarray(vals), jnp.asarray(pane_ids),
-                count, self.num_groups, backend=self.backend,
-            ))
+            parts = _launch(functools.partial(
+                pane_segagg, num_panes=count, num_groups=self.num_groups,
+                backend=self.backend), keys, vals, pane_ids)
+        parts = _spill(parts)
         for j in range(count):
             self.book.store.deposit(stream, first_pane + j, by=by,
                                     data=parts[j])
@@ -382,13 +412,9 @@ class SharedAnalyticsExecutor(BaseExecutor):
 
     def _finalize(self, query: Query, num_batches: int) -> Optional[float]:
         t0 = time.perf_counter()
-        parts = list(self._acc.get(query.query_id, {}).values())
-        total = (np.sum(np.stack(parts), axis=0) if parts
-                 else np.zeros((self.num_groups, 1), np.float32))
-        self.results[query.query_id] = total
-        dt = time.perf_counter() - t0
-        self.agg_seconds[query.query_id] = dt
-        return dt
+        self.results[query.query_id] = _merge(
+            self._acc.get(query.query_id, {}).values(), self.num_groups)
+        return time.perf_counter() - t0
 
 
 class MeshAnalyticsBackend(MeshBackend):
@@ -439,12 +465,11 @@ class MeshAnalyticsBackend(MeshBackend):
         chunk = files[offset: offset + num_tuples]
         if not chunk:
             return
-        records = concat_files(chunk)
-        keys = np.asarray(aq.key_fn(records), np.int32)
-        vals = np.asarray(aq.value_fn(records), np.float32)
+        keys, vals = _extract(aq, concat_files(chunk))
         part = self.mesh.segagg(keys, vals, self._groups[query.query_id],
                                 backend=self._segagg_backend)
-        part.block_until_ready()  # the measured dt covers the device work
+        with tracing.span("spill"):
+            part.block_until_ready()  # the measured dt covers the device work
         self._partials.setdefault(query.query_id, {})[offset] = part
 
     def _batch_execute(self, query: Query, num_tuples: int, offset: int) -> None:
@@ -463,19 +488,15 @@ class MeshAnalyticsBackend(MeshBackend):
         self._run_range(query, sum(sizes), base_offset)
 
     def _agg_execute(self, query: Query, num_batches: int) -> None:
-        parts = self._partials.get(query.query_id, {})
-        if parts:
-            total = np.sum(
-                np.stack([np.asarray(p) for p in parts.values()]), axis=0
-            )
-        else:
-            total = np.zeros((self._groups[query.query_id], 1), np.float32)
-        self.results[query.query_id] = total
+        self.results[query.query_id] = _merge(
+            self._partials.get(query.query_id, {}).values(),
+            self._groups[query.query_id])
 
     def requeue_batch(self, query: Query, num_tuples: int, offset: int) -> None:
         """Straggler redo: re-run the covering range; the offset-keyed
         partial overwrites, so no double counting."""
-        self._run_range(query, num_tuples, offset)
+        with tracing.span("executor.batch", query.query_id):
+            self._run_range(query, num_tuples, offset)
 
 
 def _plan_query(query_id: str, num_files: int) -> Query:
@@ -497,7 +518,8 @@ def run_plan(query: AnalyticsQuery, files: Sequence[Dict[str, np.ndarray]],
              backend: Optional[str] = None,
              mesh=None) -> Tuple[np.ndarray, List[BatchResult], float]:
     """Execute a scheduler plan (batch sizes in FILES) against real files
-    through the shared runtime loop (strict mode: replay the plan verbatim)."""
+    through the shared runtime loop (strict mode: replay the plan verbatim).
+    Returns (result, per-batch log, final aggregation wall seconds)."""
     rex = AnalyticsRuntimeExecutor({query.query_id: (query, files)}, scale,
                                    backend, mesh)
     q = _plan_query(query.query_id, len(files))
@@ -505,7 +527,7 @@ def run_plan(query: AnalyticsQuery, files: Sequence[Dict[str, np.ndarray]],
     return (
         rex.results[query.query_id],
         rex.physical(query.query_id).batch_log,
-        rex.agg_seconds[query.query_id],
+        rex.last_agg_wall,
     )
 
 
