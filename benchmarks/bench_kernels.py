@@ -80,8 +80,7 @@ def _pane_fn(backend):
 def _formulation(backend, n, g, v=1):
     if backend == "ref":
         return "scatter"  # segment_sum IS a scatter-add
-    return tuning.pick_formulation(
-        "interpret" if backend == "interpret" else backend, n, g, v)
+    return tuning.pick_formulation(backend, n, g, v)
 
 
 def bench_segagg(grid, reps, rows, compiled):

@@ -43,7 +43,13 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .. import tracing
 from ..core.runtime import Dispatch, WorkerBackend
-from ..kernels.segagg.ops import merge_panes, pane_composite_groups, segagg
+from ..kernels.segagg import tuning
+from ..kernels.segagg.ops import (
+    merge_panes,
+    pane_composite_groups,
+    resolve_backend,
+    segagg,
+)
 from .sharding import batch_shard_extents, batch_spec, on_fallback
 
 # Donation is a best-effort hint: platforms without buffer aliasing (CPU)
@@ -204,6 +210,10 @@ class DeviceMesh:
         for shard in k.addressable_shards:
             self.rows_placed[shard.device.id] = (
                 self.rows_placed.get(shard.device.id, 0) + shard.data.shape[0])
+        # One dispatch of one program; each device runs the formulation of
+        # its own rows.
+        tracing.count("segagg." + tuning.pick_formulation(
+            resolve_backend(backend), Np // D, num_groups, V))
         with tracing.span("kernel.dispatch"):
             return self._sharded_segagg(num_groups, backend)(k, v)
 

@@ -19,10 +19,27 @@ The legacy ``interpret: bool`` positional is still accepted (``True`` →
 ``backend="interpret"``, ``False`` → ``backend="pallas"``) so pre-dispatch
 callers keep working unchanged.
 
-Both kernel formulations (one-hot matmul vs scatter-add) exist in the
-Pallas and XLA backends; ``tuning.pick_formulation`` selects by the
-measured crossover group count, and ``tuning.tuned_blocks`` supplies
-hillclimb-tuned (block_n, block_g) per (backend, shape-class).
+Formulations (``tuning.pick_formulation``, by call shape alone):
+
+* G up to the measured crossover (``tuning.matmul_max_g``): the blocked
+  one-hot matmul, O(N·G·V) MXU work.
+* Wider G on the Pallas backends: the Pallas scatter-add while its resident
+  (G, 128-lane) f32 accumulator fits VMEM (``SCATTER_VMEM_BYTES``), else
+  ``"hbm_scatter"``: XLA's own scatter-add, ``zeros((G, V)).at[keys].add``,
+  with the accumulator in HBM and V unpadded.  A 360K- or 1.5M-group
+  GROUP-BY padded to 128 lanes needs 184-768 MB of accumulator, which no
+  VMEM holds, and the one-hot matmul there is 1e13-1e14 FLOPs per 156K
+  rows where the answer needs O(N) bytes; XLA's compiled scatter does
+  the O(N·V) work in about 1.7 ms on a TPU v5e.
+* Wider G on ``"xla"``: the same XLA scatter-add.
+
+The padding runs inside jitted programs: ``"hbm_scatter"`` is one
+program per call; the Pallas kernels are two, the row and lane padding
+(one program per row count) and the kernel with its output slice (one per
+padded shape, so row counts in one row block share a Mosaic compile).
+``tuning.tuned_blocks`` supplies hillclimb-tuned (block_n, block_g) per
+(backend, shape-class).  With ``repro.tracing`` on, each eager dispatch
+adds one to the counter ``segagg.<formulation>``.
 """
 from __future__ import annotations
 
@@ -32,6 +49,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from ... import tracing
 from . import tuning
 from .segagg import segagg_pallas
 
@@ -75,14 +93,22 @@ def _pad_to(x: int, m: int) -> int:
 
 # -- XLA formulations ------------------------------------------------------
 
-@functools.partial(jax.jit, static_argnums=(2,))
+@functools.partial(jax.jit, static_argnums=(2, 3))
 def _segagg_xla_scatter(keys: jax.Array, values: jax.Array,
-                        num_groups: int) -> jax.Array:
-    """Scatter-add: O(N·V) work regardless of G.  Out-of-range keys (the
-    contract is keys in [0, num_groups)) are dropped, matching the kernel
-    path's sacrificial padding group."""
-    return jnp.zeros((num_groups, values.shape[1]), jnp.float32).at[keys].add(
-        values.astype(jnp.float32), mode="drop")
+                        num_groups: int, block_n: int = 1) -> jax.Array:
+    """Scatter-add into a (num_groups, V) f32 accumulator in HBM: O(N·V)
+    work regardless of G.  Rows are padded to a ``block_n`` multiple with
+    key ``num_groups``; those, like any key outside [0, num_groups), are
+    dropped, as the kernel path's sacrificial padding group is."""
+    n = keys.shape[0]
+    pad = _pad_to(n, block_n) - n
+    with jax.named_scope("segagg.pad"):
+        keys = jnp.pad(keys.astype(jnp.int32), (0, pad),
+                       constant_values=num_groups)
+        values = jnp.pad(values.astype(jnp.float32), ((0, pad), (0, 0)))
+    with jax.named_scope("segagg.kernel"):
+        return jnp.zeros((num_groups, values.shape[1]), jnp.float32).at[
+            keys].add(values, mode="drop")
 
 
 _XLA_MM_BLOCK_N = 16_384  # rows per scan step: bounds the one-hot to ~G*64KB
@@ -95,6 +121,7 @@ def _segagg_xla_matmul(keys: jax.Array, values: jax.Array,
     on the MXU, expressed as XLA ops.  O(N·G·V) FLOPs — only selected for
     narrow G (below the measured crossover)."""
     N, V = values.shape
+    keys = keys.astype(jnp.int32)
     block = min(_XLA_MM_BLOCK_N, _pad_to(N, 8))
     Np = _pad_to(N, block)
     # Padding rows carry key == num_groups: outside every gid, so their
@@ -119,6 +146,36 @@ def _segagg_xla_matmul(keys: jax.Array, values: jax.Array,
     return out
 
 
+# -- Pallas formulations --------------------------------------------------
+
+@functools.partial(jax.jit, static_argnums=(2, 3, 4))
+def _pad_rows(keys: jax.Array, values: jax.Array, num_groups: int,
+              rows: int, lanes: int) -> Tuple[jax.Array, jax.Array]:
+    """Keys and values padded to ``rows`` rows, as one program: padded
+    rows carry key ``num_groups`` (the kernels' sacrificial group) and
+    values are zero-padded to ``lanes`` lanes."""
+    N, V = values.shape
+    with jax.named_scope("segagg.pad"):
+        return (jnp.pad(keys.astype(jnp.int32), (0, rows - N),
+                        constant_values=num_groups),
+                jnp.pad(values, ((0, rows - N), (0, lanes - V))))
+
+
+@functools.partial(jax.jit, static_argnums=(2, 3, 4, 5, 6, 7))
+def _segagg_pallas_sliced(keys_p: jax.Array, vals_p: jax.Array,
+                          num_groups: int, width: int, interpret: bool,
+                          block_n: int, block_g: int,
+                          formulation: str) -> jax.Array:
+    """The Pallas kernel on padded rows, sliced back to (num_groups,
+    width): one program per padded shape, whatever the unpadded row count."""
+    Gp = _pad_to(num_groups + 1, block_g)   # +1 sacrificial group for padding
+    with jax.named_scope("segagg.kernel"):
+        out = segagg_pallas(keys_p, vals_p, Gp, interpret, block_n, block_g,
+                            formulation)
+    with jax.named_scope("segagg.pad"):
+        return out[:num_groups, :width]
+
+
 # -- dispatch --------------------------------------------------------------
 
 def segagg(keys: jax.Array, values: jax.Array, num_groups: int,
@@ -129,10 +186,9 @@ def segagg(keys: jax.Array, values: jax.Array, num_groups: int,
     (num_groups, V) f32 sums.
 
     ``backend=`` selects the execution path (see module docstring);
-    ``formulation=`` overrides the matmul/scatter crossover ("matmul" |
-    "scatter", default measured per shape).  The legacy positional
-    ``interpret`` bool still works: True → the interpreter path, False →
-    compiled Pallas.
+    ``formulation=`` overrides the per-shape choice ("matmul" | "scatter" |
+    "hbm_scatter").  The legacy positional ``interpret`` bool still works:
+    True → the interpreter path, False → compiled Pallas.
     """
     be = resolve_backend(backend, interpret)
     if num_groups <= 0:
@@ -143,30 +199,24 @@ def segagg(keys: jax.Array, values: jax.Array, num_groups: int,
     V = values.shape[1]
     if N == 0:
         return jnp.zeros((num_groups, V), jnp.float32)
+    form = tuning.pick_formulation(be, N, num_groups, V, formulation)
+    if not isinstance(keys, jax.core.Tracer):  # under a jit: not a dispatch
+        tracing.count(f"segagg.{form}")
+    if form == "hbm_scatter":
+        block_n, _ = tuning.tuned_blocks(be, N, num_groups)
+        return _segagg_xla_scatter(keys, values, num_groups, block_n)
     if be == "xla":
-        form = tuning.pick_formulation(be, N, num_groups, V, formulation)
-        keys = keys.astype(jnp.int32)
         if form == "scatter":
             return _segagg_xla_scatter(keys, values, num_groups)
         return _segagg_xla_matmul(keys, values, num_groups)
-    # Pallas paths (compiled or interpreted): pad rows/groups/width to the
-    # tuned kernel blocks; padded rows are routed to a sacrificial group
-    # and sliced away.  The formulation choice sees the PADDED width — that
-    # is what the scatter accumulator keeps resident on-chip.
+    # Pallas paths: the padding is one program per row count, the kernel
+    # one per padded shape, so the Mosaic compiles are shared across row
+    # counts in one row block.
     block_n, block_g = tuning.tuned_blocks(be, N, num_groups)
-    Np = _pad_to(N, block_n)
-    Gp = _pad_to(num_groups + 1, block_g)   # +1 sacrificial group for padding
-    Vp = _pad_to(V, 128)
-    form = tuning.pick_formulation(be, N, num_groups, Vp, formulation)
-    with jax.named_scope("segagg.pad"):
-        keys_p = jnp.full((Np,), num_groups, jnp.int32).at[:N].set(
-            keys.astype(jnp.int32))
-        vals_p = jnp.zeros((Np, Vp), values.dtype).at[:N, :V].set(values)
-    with jax.named_scope("segagg.kernel"):
-        out = segagg_pallas(keys_p, vals_p, Gp, be == "interpret",
-                            block_n, block_g, form)
-    with jax.named_scope("segagg.pad"):
-        return out[:num_groups, :V]
+    keys_p, vals_p = _pad_rows(keys, values, num_groups, _pad_to(N, block_n),
+                               _pad_to(V, 128))
+    return _segagg_pallas_sliced(keys_p, vals_p, num_groups, V,
+                                 be == "interpret", block_n, block_g, form)
 
 
 def group_count(keys: jax.Array, num_groups: int,
@@ -230,11 +280,15 @@ def flops_bytes(n: int, num_groups: int, v: int, formulation: str,
     """Analytic (FLOPs, HBM bytes) of one segagg call — the numerators of
     the roofline terms (benchmarks/bench_roofline.py).  The Pallas paths
     pad rows/groups/width to kernel blocks and that padded work really
-    runs, so their counts use padded extents; the XLA paths only pad rows
-    for the matmul scan.  Matmul counts the one-hot contraction; scatter
-    one multiply-accumulate per row element.  Bytes: keys + values read,
+    runs, so their counts use padded extents; ``"hbm_scatter"`` pads only
+    rows, to the Pallas row block; the XLA paths only pad rows for the
+    matmul scan.  Matmul counts the one-hot contraction; scatter one
+    multiply-accumulate per row element.  Bytes: keys + values read,
     (G, V) f32 partial written."""
-    if backend in ("pallas", "interpret"):
+    if formulation == "hbm_scatter":
+        bn, _ = tuning.tuned_blocks(backend, n, num_groups)
+        np_, gp, vp = _pad_to(n, bn), num_groups, v
+    elif backend in ("pallas", "interpret"):
         vp = _pad_to(v, 128)
         bn, bg = tuning.tuned_blocks(backend, n, num_groups)
         np_, gp = _pad_to(n, bn), _pad_to(num_groups + 1, bg)

@@ -2,7 +2,8 @@
 query-executor hot spot — CQ1..CQ4 / TPC-H COUNT/SUM GROUP BY).
 
 Two formulations of the same segment-sum, selected per call shape by
-``ops.segagg`` (see ``tuning.crossover``):
+``ops.segagg`` (``tuning.pick_formulation``); a third, for group domains
+too wide for either, is XLA's own scatter-add in ``ops`` (below):
 
 MATMUL (DESIGN.md §2): instead of a hash table (the CPU/Spark formulation —
 pointer chasing, no TPU analogue), aggregation is a blocked ONE-HOT MATMUL
@@ -23,6 +24,13 @@ on-chip.  Work is O(N·V), independent of G, so it wins once the one-hot's
 O(N·G) FLOPs dominate; the price is a serial row loop (VPU, no MXU) and a
 resident (G, V) accumulator (must fit VMEM on real hardware — ``ops``
 checks before selecting it).
+
+Past that VMEM budget (``SCATTER_VMEM_BYTES``; the width is padded to 128
+lanes, so about 16K groups) neither kernel here runs: ``ops.segagg``
+hands the call to ``"hbm_scatter"``, a jitted XLA scatter-add whose
+(G, V) accumulator stays in HBM at its unpadded width.  The one-hot
+matmul at CQ3/CQ4 widths (G = 360K, 1.5M) would spend 1e13-1e14 MXU FLOPs
+per 156K rows on an answer that needs O(N) bytes.
 
 Batches of rows become independent partial aggregates; the paper's "final
 aggregation" is then a trivial add over partials (`combine`), whose cost

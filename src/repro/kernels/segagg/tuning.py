@@ -70,20 +70,36 @@ def matmul_max_g(backend: str) -> int:
     return DEFAULT_MATMUL_MAX_G
 
 
+FORMULATIONS = ("matmul", "scatter", "hbm_scatter")
+
+
 def pick_formulation(backend: str, n: int, g: int, v: int,
                      override: Optional[str] = None) -> str:
-    """matmul vs scatter for one call shape.  The scatter variant keeps the
-    full (G, V) accumulator resident (VMEM on TPU), so it is only eligible
-    while that fits ``SCATTER_VMEM_BYTES``."""
+    """The formulation ``ops.segagg`` runs for ``n`` rows of width ``v``
+    into ``g`` groups on a resolved ``backend``, from the shape alone.  The
+    Pallas kernels see the width padded to 128 lanes: that is what their
+    scatter accumulator keeps resident.
+
+    * ``g <= matmul_max_g(backend)``: the one-hot matmul.
+    * Otherwise, on ``"pallas"``/``"interpret"``: the Pallas scatter-add
+      while its resident (G, V) accumulator fits ``SCATTER_VMEM_BYTES``,
+      else ``"hbm_scatter"``, XLA's scatter-add into an accumulator in
+      HBM.  A wide G padded to 128 lanes outgrows VMEM long before the
+      one-hot matmul's O(N·G·V) MXU work stops mattering, so the matmul is
+      never the fallback.
+    * Otherwise, on ``"xla"``: the XLA scatter-add (its accumulator is in
+      HBM already).
+    """
     if override is not None:
-        if override not in ("matmul", "scatter"):
+        if override not in FORMULATIONS:
             raise ValueError(f"unknown segagg formulation: {override!r} "
-                             "(expected 'matmul' or 'scatter')")
+                             f"(expected one of {FORMULATIONS})")
         return override
     if g <= matmul_max_g(backend):
         return "matmul"
-    if backend in ("pallas", "interpret") and g * v * 4 > SCATTER_VMEM_BYTES:
-        return "matmul"  # scatter accumulator would not fit on-chip
+    lanes = -(-v // 128) * 128
+    if backend in ("pallas", "interpret") and g * lanes * 4 > SCATTER_VMEM_BYTES:
+        return "hbm_scatter"
     return "scatter"
 
 

@@ -19,6 +19,12 @@ host plane next to the device operations.  ``perf_counter`` is the clock a
 caller maps a profiler trace onto, so spans and device operations compare
 with no further work.
 
+Counters (``count(name)``) tally how often a step takes one path or
+another, under the same switch: off, a count is one flag check; on, it
+adds one to ``name``; ``drain_counts()`` returns and clears the tallies.
+The kernel layer counts its dispatches per formulation
+(``segagg.matmul``, ``segagg.scatter``, ``segagg.hbm_scatter``).
+
 Parents come from a stack: the session loop is single-threaded.  A span's
 ``request`` is the window's ``query_id`` where the caller knows one, else
 its parent's.  This is the wall-clock view of a run; ``SessionTrace``
@@ -28,9 +34,10 @@ from __future__ import annotations
 
 import dataclasses
 import time
-from typing import List, Optional
+from typing import Dict, List, Optional
 
-__all__ = ["Span", "span", "enable", "disable", "drain"]
+__all__ = ["Span", "span", "count", "enable", "disable", "drain",
+           "drain_counts"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -57,6 +64,7 @@ _OFF = _Off()
 _on = False
 _annotation = None            # jax.profiler.TraceAnnotation, bound by enable()
 _recorded: List[Span] = []
+_counts: Dict[str, int] = {}
 _open: List["_Live"] = []
 _next_id = 0
 
@@ -98,6 +106,12 @@ def span(name: str, request: Optional[str] = None):
     return _Live(name, request)
 
 
+def count(name: str) -> None:
+    """Add one to the counter ``name`` (nothing while tracing is off)."""
+    if _on:
+        _counts[name] = _counts.get(name, 0) + 1
+
+
 def enable() -> None:
     """Record spans from now on (and annotate the profiler's trace)."""
     global _on, _annotation
@@ -116,4 +130,11 @@ def drain() -> List[Span]:
     """The spans closed since the last drain, in the order they closed."""
     global _recorded
     out, _recorded = _recorded, []
+    return out
+
+
+def drain_counts() -> Dict[str, int]:
+    """The counters tallied since the last ``drain_counts``."""
+    global _counts
+    out, _counts = _counts, {}
     return out

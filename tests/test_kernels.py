@@ -156,7 +156,7 @@ class TestSegAggBackends:
         assert got.sum() == float(n)
 
     @pytest.mark.parametrize("backend", SEGAGG_BACKENDS)
-    @pytest.mark.parametrize("formulation", ["matmul", "scatter"])
+    @pytest.mark.parametrize("formulation", ["matmul", "scatter", "hbm_scatter"])
     def test_formulation_override_parity(self, backend, formulation):
         key = jax.random.PRNGKey(5)
         keys = jax.random.randint(key, (900,), 0, 41)

@@ -6,6 +6,8 @@ Pinned properties:
 * off (the default) records nothing and enters no profiler annotation;
   on, nested spans get their parent and inherit the request id, and
   ``drain`` empties the record;
+* the segagg formulation counter: nothing while off; on, one count per
+  ``segagg`` call under the formulation that ran;
 * tracing is an observer: a session over the real segagg executor gives
   the same answers and the same ``SessionTrace`` with it on and off, and
   every ``executor.batch`` holds the scan's five steps;
@@ -26,6 +28,7 @@ import pytest
 from repro import tracing
 from repro.core import LinearCostModel
 from repro.data.tpch import PAPER_QUERIES, StreamScale, stream_files
+from repro.kernels.segagg.ops import segagg
 from repro.kernels.segagg.ref import segagg_numpy
 from repro.serve.analytics import concat_files, run_session
 
@@ -55,9 +58,11 @@ def tracer(monkeypatch):
     Annotations.entered = []
     tracing.disable()
     tracing.drain()
+    tracing.drain_counts()
     yield tracing
     tracing.disable()
     tracing.drain()
+    tracing.drain_counts()
 
 
 def test_off_records_nothing_and_enters_no_annotation(tracer):
@@ -101,6 +106,31 @@ def test_span_open_at_disable_is_kept(tracer):
         with tracer.span("after"):
             pass
     assert [s.name for s in tracer.drain()] == ["outer"]
+
+
+def _segagg_calls(groups, calls=2):
+    keys = np.arange(300, dtype=np.int32) % groups
+    for _ in range(calls):
+        segagg(keys, np.ones((300, 1), np.float32), groups,
+               backend="interpret")
+
+
+def test_counter_off_counts_nothing(tracer):
+    _segagg_calls(20_000)
+    tracer.count("x")
+    assert tracer.drain_counts() == {}
+
+
+@pytest.mark.parametrize("groups,form", [
+    (5, "matmul"), (4_096, "scatter"), (20_000, "hbm_scatter")])
+def test_counter_counts_each_dispatch_under_its_formulation(
+        tracer, groups, form):
+    tracer.enable()
+    _segagg_calls(groups, calls=3)
+    tracer.disable()
+    _segagg_calls(groups)                 # off again: not counted
+    assert tracer.drain_counts() == {f"segagg.{form}": 3}
+    assert tracer.drain_counts() == {}    # drained
 
 
 SCALE = StreamScale(scale=0.005)
