@@ -135,19 +135,22 @@ def label(gap: Interval, spans: Sequence[tuple]) -> str:
 
 
 def breakdown(ops: Dict[int, List[tuple]], spans: Sequence[tuple],
-              lo: float, hi: float, top: int = 10) -> dict:
-    """Device operations that took most time in [lo, hi], summed by name
-    over the chips, and the longest gaps in which no chip ran an operation,
-    each named by the host span it fell in."""
+              live: Sequence[Interval], top: int = 10) -> dict:
+    """Device operations that took most time in the disjoint ``live``
+    intervals (the measured window), summed by name over the chips, and the
+    longest gaps in them in which no chip ran an operation, each named by
+    the host span it fell in."""
     by_name: Dict[str, float] = defaultdict(float)
     for evs in ops.values():
         for s, e, n in evs:
-            by_name[n] += max(0.0, min(e, hi) - max(s, lo))
+            by_name[n] += covered(live, s, e)
     busiest = sorted(by_name.items(), key=lambda kv: -kv[1])[:top]
     every = union([iv for evs in ops.values() for iv in evs])
+    lo, hi = live[0][0], live[-1][1]
     inside = sorted(((n, s, e) for n, s, e in spans if e > lo and s < hi),
                     key=lambda x: x[1])
-    longest = sorted(gaps(every, lo, hi), key=lambda g: g[0] - g[1])[:top]
+    idle = [g for s, e in live for g in gaps(every, s, e)]
+    longest = sorted(idle, key=lambda g: g[0] - g[1])[:top]
     return {"device_ops": [[n, t] for n, t in busiest],
             "idle_gaps": [[label(g, inside), g[1] - g[0]] for g in longest]}
 

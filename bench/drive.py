@@ -11,6 +11,10 @@ of the configuration, each window's ticks drawn from the pool in the seeded
 order.  Paced traffic places tick ``j`` of a round at ``(j + 1) / rate``
 seconds of modelled time; unpaced traffic places every tick at the start, so
 the whole round is a backlog.
+
+Between rounds the clock stops: the round's answers are compared with the
+plain reference and let go, as a deployment lets an answer go once it has
+been emitted, so the harness never holds more than one round's answers.
 """
 from __future__ import annotations
 
@@ -18,7 +22,7 @@ import dataclasses
 import math
 import sys
 import time
-from typing import Dict, List, Optional
+from typing import Callable, Dict, List, Optional
 
 import numpy as np
 
@@ -168,20 +172,40 @@ class Cell:
                     close=t0 + stamps[w][-1],
                     deadline=t0 + stamps[w][-1] + offset,
                     emitted=rec.emitted.get(qid),
-                    result=physical.results.get(qid),
+                    result=physical.results.pop(qid, None),
                     batches=out.num_batches if out is not None else 0))
         return t0
 
-    def measure(self, seconds: float) -> tuple:
-        """Rounds until ``seconds`` have passed since the first began.
-        Returns the measured window ``(lo, hi)`` on the wall."""
+    def measure(self, seconds: float,
+                check: Callable[[List[Window]], None]) -> tuple:
+        """Rounds until ``seconds`` of their own time have passed.  The clock
+        starts at the first round's anchor and runs through each later round
+        from the start of its set-up, so building its ``Session`` counts.  It
+        stops from a round's last answer until the next round starts: there,
+        ``check`` compares the round's windows due in the window with the
+        reference, and their answers are let go (a ``check`` span).  Returns
+        the measured window ``(lo, hi)`` on the wall; ``hi - lo`` is
+        ``seconds`` and the checks before ``hi``.  Paced traffic has one
+        round, sized to cover ``seconds``."""
         per_round = self.traffic["windows_per_round"]
-        lo = None
-        while lo is None or time.perf_counter() < lo + seconds:
+        lo, paused = None, 0.0
+        while True:
             n = per_round or math.ceil(seconds / self.period) + 1
+            first = len(self.windows)
             t0 = self.run_round(n)
             lo = t0 if lo is None else lo
-        return lo, lo + seconds
+            hi = lo + paused + seconds
+            new = self.windows[first:]
+            end = max((w.emitted for w in new if w.emitted is not None),
+                      default=time.perf_counter())
+            check([w for w in new if w.close <= hi])
+            for w in new:
+                w.result = None
+            if per_round is None or end >= hi:
+                return lo, hi
+            resume = time.perf_counter()
+            self.rec.spans.append(("check", end, resume))
+            paused += resume - end
 
     def warm_up(self) -> None:
         """One untimed round: compiles the batch shapes the scheduler picks."""

@@ -37,11 +37,18 @@ def host_share(run) -> Optional[float]:
     return 100.0 * (1.0 - dev / (run.chips * total))
 
 
+def measured(run, left_out: Tuple[str, ...] = ("check",)) -> List[Tuple[float, float]]:
+    """The measured window less the spans named in ``left_out``: by default
+    the reference checks between rounds, in which the clock stops."""
+    off = [iv for name in left_out for iv in spans(run, name)]
+    return device_trace.gaps(device_trace.union(off), run.lo, run.hi)
+
+
 def idle_share(run, without_waits: bool) -> Optional[float]:
-    """% of the window in which the device ran no operation (averaged over
-    the chips); with ``without_waits`` the pacing waits leave the window."""
-    waits = device_trace.union(spans(run, "wait")) if without_waits else []
-    live = device_trace.gaps(waits, run.lo, run.hi)
+    """% of the measured window in which the device ran no operation
+    (averaged over the chips); the checks between rounds leave the window,
+    and with ``without_waits`` the pacing waits too."""
+    live = measured(run, ("check", "wait") if without_waits else ("check",))
     total = sum(e - s for s, e in live)
     dev = device_seconds(run, live)
     if dev is None or total <= 0:

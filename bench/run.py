@@ -6,10 +6,11 @@ The cell, its configuration (``bench/configs/``), its traffic mix
 (``bench/traffic/``) and its metrics (``bench/metrics/<name>.py``, each a
 ``read(run)`` function) are found by name through ``BENCHMARK.json``.  The
 run makes its data from ``--seed``, calibrates the program's cost models,
-warms up, measures for ``--seconds``, checks every answer due in the window
-against the plain reference, and prints one JSON object as the last line of
-standard output.  ``--trace 1`` profiles the measured window and reports
-the per-layer metrics instead of the end-to-end ones.
+warms up, measures for ``--seconds`` of the rounds' own time, checks every
+answer due in the window against the plain reference (round by round, with
+the clock stopped between rounds), and prints one JSON object as the last
+line of standard output.  ``--trace 1`` profiles the measured window and
+reports the per-layer metrics instead of the end-to-end ones.
 
 It needs a TPU: on any other platform it prints the platform and exits
 non-zero with no result.  ``--rehearse`` is the one exception, a CPU
@@ -24,9 +25,11 @@ T_START = time.perf_counter()
 
 import argparse  # noqa: E402
 import dataclasses  # noqa: E402
+import gc  # noqa: E402
 import importlib.util  # noqa: E402
 import json  # noqa: E402
 import pathlib  # noqa: E402
+import resource  # noqa: E402
 import sys  # noqa: E402
 from typing import List, Optional  # noqa: E402
 
@@ -51,6 +54,11 @@ class RunView:
     busy: Optional[List[list]] = None   # per chip: disjoint busy intervals
 
 
+def rss_bytes() -> int:
+    with open("/proc/self/statm") as f:
+        return int(f.read().split()[1]) * resource.getpagesize()
+
+
 class CompileClock:
     """Compilations JAX reports, and their seconds (a persistent cache hit
     counts with its retrieval time)."""
@@ -62,6 +70,44 @@ class CompileClock:
         if event.startswith("/jax/core/compile/"):
             self.seconds += duration
             self.count += 1
+
+
+class GcClock:
+    """Python's GC passes, as ``gc.callbacks`` reports them:
+    ``(start, seconds, generation)``."""
+
+    def __init__(self):
+        self.passes, self._start = [], None
+
+    def __call__(self, phase: str, info: dict) -> None:
+        if phase == "start":
+            self._start = time.perf_counter()
+        elif self._start is not None:
+            self.passes.append((self._start, time.perf_counter() - self._start,
+                                info["generation"]))
+            self._start = None
+
+
+def minor_faults() -> int:
+    return resource.getrusage(resource.RUSAGE_SELF).ru_minflt
+
+
+def print_host(gc_clock: GcClock, faults: int, rss: tuple,
+               live: List[tuple]) -> None:
+    """Minor page faults of the rounds and the checks between them,
+    resident bytes at the window's start and end, peak RSS, and the GC
+    passes of the measured time: a host stall becomes a reading.  A
+    sandboxed host may not count page faults (``ru_minflt`` reads 0); the
+    resident bytes show the heap's growth there."""
+    inside = [p for p in gc_clock.passes
+              if any(s <= p[0] < e for s, e in live)]
+    gens = [sum(1 for p in inside if p[2] == g) for g in (0, 1, 2)]
+    maxrss_kb = resource.getrusage(resource.RUSAGE_SELF).ru_maxrss
+    print(f"host in the window: minor_faults={faults} "
+          f"rss_bytes={rss[0]}->{rss[1]} max_rss_bytes={maxrss_kb * 1024} "
+          f"gc_seconds={sum(p[1] for p in inside)} gc_passes_by_gen={gens} "
+          f"longest_gc_s={max((p[1] for p in inside), default=0.0)}",
+          file=sys.stderr)
 
 
 def print_stalls(rec, lo: float, hi: float) -> None:
@@ -133,6 +179,7 @@ def main(argv=None) -> int:
 
     import device_trace
     import drive
+    import readings
     import reference
     from repro.compile_cache import enable_compile_cache
 
@@ -145,14 +192,24 @@ def main(argv=None) -> int:
     else:
         scale, backend = 1.0, "pallas"
     run = drive.Cell(config, traffic, args.seed, scale, backend, args.rate)
+    ref_s = time.perf_counter()
+    check = reference.Check(reference.Reference(config, run.pool, scale))
+    ref_s = time.perf_counter() - ref_s
+    print(f"reference set-up, left out of setup_s: seconds={ref_s}",
+          file=sys.stderr)
     run.calibrate()
     run.warm_up()
+    gc_clock = GcClock()
     profile = (device_trace.Profile(f"{args.workload}-{args.seed}")
                if args.trace else None)
     if profile:
         profile.start()
     c_sec, c_num = clock.seconds, clock.count
-    lo, hi = run.measure(args.seconds)
+    gc.callbacks.append(gc_clock)
+    faults, rss = minor_faults(), rss_bytes()
+    lo, hi = run.measure(args.seconds, check)
+    faults, rss = minor_faults() - faults, (rss, rss_bytes())
+    gc.callbacks.remove(gc_clock)
     if profile:
         profile.stop()
     print(f"compiles in the window: count={clock.count - c_num} "
@@ -166,19 +223,20 @@ def main(argv=None) -> int:
           file=sys.stderr)
     print_stalls(run.rec, lo, hi)
 
-    view = RunView(lo=lo, hi=hi, setup_s=lo - T_START, rec=run.rec,
+    view = RunView(lo=lo, hi=hi, setup_s=lo - T_START - ref_s, rec=run.rec,
                    windows=[w for w in run.windows if lo <= w.close <= hi],
-                   chips=cell["chips"],
-                   device_kind=device["kind"])
+                   chips=cell["chips"], device_kind=device["kind"])
+    live = readings.measured(view)
+    print_host(gc_clock, faults, rss, live)
     result = {}
     if profile:
         ops, layout = profile.read()
         device_trace.print_layout(layout)
         view.busy = [device_trace.union(ops.get(d.id, [])) for d in used]
-        device["busy_s"] = sum(device_trace.covered(b, lo, hi)
+        device["busy_s"] = sum(device_trace.covered_in(b, live)
                                for b in view.busy) / len(used)
-        device["window_s"] = hi - lo
-        result["breakdown"] = device_trace.breakdown(ops, run.rec.spans, lo, hi)
+        device["window_s"] = sum(e - s for s, e in live)
+        result["breakdown"] = device_trace.breakdown(ops, run.rec.spans, live)
 
     metrics = {}
     for m in cell_metrics(spec, args.workload, bool(args.trace)):
@@ -196,9 +254,7 @@ def main(argv=None) -> int:
                  if w.emitted is not None and w.emitted > w.deadline)
     print(f"deadlines: answered late={n_late} never={failed} "
           f"of {len(view.windows)}", file=sys.stderr)
-    ref = reference.Reference(config, run.pool, scale)
-    numbers = reference.compare(ref, view.windows)
-    limits = config["limits"]
+    limits, numbers = config["limits"], check.numbers
     correct = reference.verdict(numbers, limits) and bool(view.windows)
     out = {"correct": correct, "attempted": len(view.windows), "failed": failed,
            "metrics": metrics, "device": device, **result}

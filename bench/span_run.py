@@ -102,6 +102,7 @@ def main(argv=None) -> int:
     else:
         scale, backend = 1.0, "pallas"
     cell_run = drive.Cell(config, traffic, args.seed, scale, backend)
+    check = reference.Check(reference.Reference(config, cell_run.pool, scale))
     cell_run.calibrate()
     cell_run.warm_up()
     profile = (program_spans.ScopedProfile(f"spans-{args.workload}-{args.seed}")
@@ -110,7 +111,7 @@ def main(argv=None) -> int:
     tracing.enable()
     if profile:
         profile.start()
-    lo, hi = cell_run.measure(args.seconds)
+    lo, hi = cell_run.measure(args.seconds, check)
     if profile:
         profile.stop()
     tracing.disable()
@@ -146,11 +147,9 @@ def main(argv=None) -> int:
            "spans": len(spans),
            # A CPU rehearsal's times are no device readings.
            ("rehearsal_readings" if args.rehearse else "readings"): found}
-    numbers = reference.compare(reference.Reference(config, cell_run.pool, scale),
-                                view.windows)
-    out["correct"] = (reference.verdict(numbers, config["limits"])
+    out["correct"] = (reference.verdict(check.numbers, config["limits"])
                       and bool(view.windows))
-    out["checks"] = numbers
+    out["checks"] = check.numbers
     print(json.dumps(out))
     return 0
 

@@ -29,12 +29,20 @@ def test_gaps_are_named_by_the_span_they_fell_in():
     assert device_trace.label((8.0, 9.0), SPANS) == "none"
 
 
-def test_breakdown_sums_ops_by_name_and_lists_the_longest_gaps():
+@pytest.mark.parametrize("live,ops_s,idle", [
+    # the whole window: every chip idle (3, 3.5) and (4, 5) in
+    # batch/finalize, (6, 7) in decide
+    ([(0.0, 7.0)], [["a", 3.0], ["b", 2.5]],
+     [["batch", 1.0], ["decide", 1.0], ["batch", 0.5]]),
+    # a check from 3.25 to 4.25 leaves the window, and chip 1's op with it
+    ([(0.0, 3.25), (4.25, 7.0)], [["a", 3.0], ["b", 2.0]],
+     [["decide", 1.0], ["finalize", 0.75], ["batch", 0.25]]),
+])
+def test_breakdown_sums_ops_by_name_and_lists_the_longest_gaps(live, ops_s, idle):
     ops = {0: OPS, 1: [(3.5, 4.0, "b")]}
-    out = device_trace.breakdown(ops, SPANS, 0.0, 7.0)
-    assert out["device_ops"] == [["a", 3.0], ["b", 2.5]]
-    # every chip idle: (3, 3.5) and (4, 5) in batch/finalize, (6, 7) decide
-    assert out["idle_gaps"] == [["batch", 1.0], ["decide", 1.0], ["batch", 0.5]]
+    out = device_trace.breakdown(ops, SPANS, live)
+    assert out["device_ops"] == ops_s
+    assert out["idle_gaps"] == idle
 
 
 def test_groupby_work_counts_rows_groups_and_values_only():
@@ -79,7 +87,7 @@ def test_recorded_gaps_fall_in_the_host_pauses():
     for name, s, e in spans:   # the pauses hold at most a stray microsecond
         busy = device_trace.covered(merged, s, e)
         assert (busy > 0.01 * (e - s)) == (name == "batch"), (name, busy)
-    out = device_trace.breakdown(ops, spans, lo, hi)
+    out = device_trace.breakdown(ops, spans, [(lo, hi)])
     names = [name for name, _ in out["idle_gaps"]]
     # the 3 ms wait lies inside the longest gap, which is named after it
     wait = next((s, e) for name, s, e in spans if name == "wait")
